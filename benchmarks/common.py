@@ -1,4 +1,8 @@
-"""Shared benchmark utilities: timing, table formatting, result capture."""
+"""Shared benchmark utilities: timing, table formatting, result capture.
+
+Every bench imports this module, so importing it turns on the persistent
+compilation cache (``repro.launch.compile_cache``).
+"""
 from __future__ import annotations
 
 import json
@@ -6,6 +10,10 @@ import os
 import subprocess
 import time
 from typing import Callable, Dict, List, Optional
+
+from repro.launch import compile_cache
+
+compile_cache.enable()
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "..", "artifacts",
                          "bench")

@@ -286,11 +286,10 @@ def run():
 
     out = {"devices": len(jax.devices()),
            "backend": jax.default_backend(),
-           # wall numbers on a forced CPU mesh measure virtual-device
+           # wall numbers on a CPU mesh measure virtual-device
            # overhead, not distributed planning time — flagged so the
            # perf trajectory never reads them as a regression
-           "emulated_mesh": "xla_force_host_platform_device_count"
-                            in os.environ.get("XLA_FLAGS", "")}
+           "emulated_mesh": jax.devices()[0].platform == "cpu"}
     _bench_scenarios(out)
     _bench_pic(out)
     _bench_resilience(out)

@@ -1,8 +1,4 @@
 """Reproduction of "Communication-Aware Diffusion Load Balancing for
 Persistently Interacting Objects" grown toward a production-scale JAX
-system.  Importing the package installs version shims for newer
-``jax.sharding`` APIs on the pinned jax (see distributed/compat.py).
+system.
 """
-from repro.distributed import compat as _compat
-
-_compat.install()

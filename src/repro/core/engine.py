@@ -23,8 +23,8 @@ iteration caps)`` and exposes
 
 Stage 2 runs the chunked virtual-LB loop (``sweep_chunk`` sweeps per
 ``while_loop`` body) through ``kernels.diffusion.ops.diffusion_nsweeps``,
-which picks the fused multi-sweep Pallas kernel / streaming kernel /
-compiled reference per backend and VMEM budget.
+which selects the implementation (the XLA-compiled reference chunk on
+every backend; see that module for why).
 
 ``Strategy`` is the registry protocol replacing the dict-of-lambdas in
 ``core/api.py`` (a thin mapping view remains there for back-compat):
@@ -104,9 +104,9 @@ class LBEngine:
         # optional stage 4 (paper §III.D): within-node LPT across T threads
         self.threads_per_node = (None if threads_per_node is None
                                  else int(threads_per_node))
-        # production stage-2 path: the fused S-sweep chunk (auto-selected
-        # fused/streaming/reference in kernels/diffusion/ops.py); an
-        # explicit step_fn opts out and runs per-sweep inside the chunk.
+        # production stage-2 path: the S-sweep chunk dispatched by
+        # kernels/diffusion/ops.py; an explicit step_fn opts out and runs
+        # per-sweep inside the chunk.
         self.chunk_fn = (diffusion_ops.diffusion_nsweeps
                          if step_fn is None else None)
         self._jitted = jax.jit(self.plan_fn)

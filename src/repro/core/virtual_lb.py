@@ -15,8 +15,7 @@ neighbor j exactly what j's row pushed toward i, located via the precomputed
 reverse-slot table.  This keeps the sweep gather-only (no scatters), which is
 what the Pallas kernel (kernels/diffusion) exploits.
 
-The inner sweep is pluggable: ``step_fn=None`` uses the pure-jnp reference;
-the production path passes ``kernels.diffusion.ops.diffusion_sweep``.
+The inner sweep is pluggable: ``step_fn=None`` uses the pure-jnp reference.
 
 The fixed-point loop runs in *chunks*: ``virtual_balance`` is a
 ``jax.lax.while_loop`` over ``sweep_chunk``-sweep blocks, each block
@@ -26,8 +25,7 @@ host-visible loop condition is evaluated — the per-sweep activity mask
 replicates the per-sweep ``while_loop`` decisions exactly, so results are
 bit-for-bit independent of ``sweep_chunk``.  ``chunk_fn`` swaps in a fused
 implementation of the whole S-sweep block (the production path passes
-``kernels.diffusion.ops.diffusion_nsweeps``, which keeps the neighbor
-tables VMEM-resident across the block on TPU).
+``kernels.diffusion.ops.diffusion_nsweeps``).
 """
 from __future__ import annotations
 
@@ -63,8 +61,8 @@ def reference_sweep(x, own, nbr_idx, nbr_mask, rev, alpha, single_hop):
     Pure-jnp oracle for the Pallas kernel (kernels/diffusion/ref.py re-exports
     this).  Gather-only; see module docstring.
     """
-    # gathers use the flattened jnp.take(..., mode="clip") forms the native
-    # TPU kernels lower (see kernels/diffusion); a gather copies elements
+    # gathers use the flattened jnp.take(..., mode="clip") forms the Pallas
+    # kernels share (see kernels/diffusion); a gather copies elements
     # exactly, so this is bit-identical to advanced indexing
     safe_nbr = jnp.where(nbr_mask, nbr_idx, 0)
     xn = jnp.where(nbr_mask, jnp.take(x, safe_nbr, axis=0, mode="clip"),

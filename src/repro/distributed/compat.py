@@ -1,69 +1,14 @@
-"""Version-guarded shims for jax.sharding APIs newer than the pinned jax.
+"""Profiler hooks shared by the instrumented code paths.
 
-The pinned jax (0.4.37) predates ``jax.sharding.get_abstract_mesh``,
-``jax.sharding.set_mesh``, ``jax.sharding.AxisType`` and the top-level
-``jax.shard_map``.  The code base is written against the newer spelling;
-``install()`` (called from ``repro/__init__.py``) backfills the missing
-names so both old and new jax work unchanged.  On a new-enough jax every
-shim is a no-op and the native implementation is used.
+Thin names over ``jax.named_scope`` and ``jax.profiler`` so call sites
+read the same everywhere.  They catch nothing: a profiler that fails to
+start raises where it was asked for.
 """
 from __future__ import annotations
 
 import contextlib
-import enum
 
 import jax
-
-
-def get_abstract_mesh():
-    """Ambient mesh: native ``jax.sharding.get_abstract_mesh`` when present,
-    else the thread-local physical mesh set by ``with mesh:`` / ``set_mesh``.
-
-    Both return an object with ``.empty``, ``.axis_names`` and
-    ``.axis_sizes`` — the only attributes our call sites touch."""
-    native = getattr(jax.sharding, "get_abstract_mesh", None)
-    if native is not None and native is not get_abstract_mesh:
-        return native()  # pragma: no cover - new-jax path
-    from jax._src import mesh as mesh_lib
-
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
-@contextlib.contextmanager
-def _set_mesh_compat(mesh):
-    """Old-jax stand-in for ``jax.sharding.set_mesh``: a Mesh is already a
-    context manager that installs itself as the ambient mesh."""
-    with mesh:
-        yield mesh
-
-
-def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=None, **kw):
-    """Old-jax stand-in for ``jax.shard_map`` (``check_vma`` → ``check_rep``)."""
-    from jax.experimental.shard_map import shard_map as _sm
-
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-class _AxisType(enum.Enum):
-    """Placeholder for ``jax.sharding.AxisType`` (auto is the 0.4.x default)."""
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-def _make_mesh_compat(axis_shapes, axis_names, *, axis_types=None, **kw):
-    del axis_types  # 0.4.37 meshes have no axis types (all Auto)
-    return _make_mesh_compat.native(axis_shapes, axis_names, **kw)
-
-
-# ------------------------------------------------------------- profiler --
-#
-# Thin wrappers so instrumented code never has to care whether the pinned
-# jax ships the profiler API (CPU-only wheels and very old jax may not):
-# every helper degrades to a no-op context manager.
 
 
 def named_scope(name: str):
@@ -72,46 +17,17 @@ def named_scope(name: str):
     Names the enclosed ops in XLA HLO metadata, so ``jax.profiler`` traces
     and HLO dumps attribute time to the planner stage / kernel dispatch /
     exchange that spent it.  Free when no profiler is attached."""
-    try:
-        return jax.named_scope(name)
-    except Exception:  # pragma: no cover - ancient jax
-        return contextlib.nullcontext()
+    return jax.named_scope(name)
 
 
 def trace_annotation(name: str):
     """Host-side profiler region (``jax.profiler.TraceAnnotation``)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler-less build
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def profiler_trace(log_dir):
-    """``jax.profiler.trace(log_dir)`` — no-op when ``log_dir`` is falsy
-    or the runtime has no profiler (the launchers' ``--profile-dir``)."""
+    """``jax.profiler.trace(log_dir)``; a no-op context when ``log_dir``
+    is falsy (the launchers' ``--profile-dir`` left unset)."""
     if not log_dir:
         return contextlib.nullcontext()
-    try:
-        return jax.profiler.trace(log_dir)
-    except Exception:  # pragma: no cover - profiler-less build
-        return contextlib.nullcontext()
-
-
-def install() -> None:
-    """Backfill missing jax.sharding / jax names (idempotent)."""
-    if not hasattr(jax.sharding, "get_abstract_mesh"):
-        jax.sharding.get_abstract_mesh = get_abstract_mesh
-    if not hasattr(jax.sharding, "set_mesh"):
-        jax.sharding.set_mesh = _set_mesh_compat
-    if not hasattr(jax.sharding, "AxisType"):
-        jax.sharding.AxisType = _AxisType
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _shard_map_compat
-    import inspect
-
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _make_mesh_compat.native = jax.make_mesh
-        jax.make_mesh = _make_mesh_compat
-
-
-install()
+    return jax.profiler.trace(log_dir)

@@ -61,7 +61,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P_
 
-from repro.distributed import compat  # noqa: F401  (installs jax.shard_map)
 from repro.core import comm_graph, hierarchical
 from repro.core import engine as core_engine
 from repro.core import neighbor_selection as ns

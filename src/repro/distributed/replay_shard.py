@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P_
 
-from repro.distributed import compat  # noqa: F401  (installs jax.shard_map)
+from repro.distributed import compat
 from repro.distributed import lb_shard
 from repro.core import comm_graph, metrics
 from repro.core import engine as core_engine

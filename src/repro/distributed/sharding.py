@@ -16,7 +16,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import get_abstract_mesh  # noqa: F401  (re-export)
 from repro.models import params as params_lib
 
 BATCH = ("pod", "data")

@@ -1,11 +1,12 @@
-from repro.kernels.diffusion.ops import (
-    diffusion_sweep,
-    diffusion_sweep_reference,
+from repro.kernels.diffusion.ops import diffusion_nsweeps, sweep_impl
+from repro.kernels.diffusion.kernel import (
+    diffusion_nsweeps_pallas,
+    diffusion_sweep_pallas,
 )
-from repro.kernels.diffusion.kernel import diffusion_sweep_pallas
 
 __all__ = [
-    "diffusion_sweep",
+    "diffusion_nsweeps",
+    "diffusion_nsweeps_pallas",
     "diffusion_sweep_pallas",
-    "diffusion_sweep_reference",
+    "sweep_impl",
 ]

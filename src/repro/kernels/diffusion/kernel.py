@@ -2,10 +2,13 @@
 
 The sweep is the iterated hot loop of the balancer: at simulator scale
 (P ~ 10^5-10^6 nodes, K ≤ 16 neighbors) hundreds of sweeps run per LB round.
-Two kernels cover the P spectrum (``ops.py`` selects automatically):
+Two kernels cover the P spectrum.  Neither compiles for a TPU: Mosaic
+refuses their neighbor gathers (``jnp.take`` over a whole VMEM vector or
+table) and their ``dynamic_slice`` of loaded refs, so ``ops.py``
+dispatches to neither and they run only in the interpret-mode tests.
 
-**Fused multi-sweep kernel** (``diffusion_nsweeps_pallas``) — the default
-when the working set fits the VMEM budget.  One ``pallas_call`` runs S
+**Fused multi-sweep kernel** (``diffusion_nsweeps_pallas``) — for working
+sets that fit VMEM.  One ``pallas_call`` runs S
 sweeps back-to-back: the neighbor/mask/reverse tables are loaded into VMEM
 *once per S-sweep block* (instead of twice per sweep), push+recv fuse into
 a single gather-only pass per sweep via the symmetric-graph identity
@@ -17,8 +20,8 @@ checks (convergence / iteration cap / stall), so the block is bit-for-bit
 equal to S steps of the per-sweep loop — the sweep body is the *shared*
 ``core.virtual_lb.sweep_chunk_body``, identical by construction.
 
-**Streaming two-pass kernel** (``diffusion_sweep_pallas``) — the large-P
-fallback.  Computes one sweep with the tables streamed through VMEM in
+**Streaming two-pass kernel** (``diffusion_sweep_pallas``) — for large
+P.  Computes one sweep with the tables streamed through VMEM in
 ``block_p`` node blocks (touched once per pass):
   * the load vector ``x`` (P f32 ≤ 4 MB at P = 10^6) and ``own`` stay fully
     VMEM-resident for every grid step — they are the gather targets;
@@ -27,9 +30,9 @@ fallback.  Computes one sweep with the tables streamed through VMEM in
     outputs — gather-only, no scatters (scatters serialize on TPU).
 
 The fixed-point loop lives in ``core/virtual_lb.py`` (a
-``jax.lax.while_loop`` over S-sweep chunks); ``ops.diffusion_nsweeps`` is
-the production ``chunk_fn`` and ``ops.diffusion_sweep`` the per-sweep
-``step_fn``.
+``jax.lax.while_loop`` over S-sweep chunks); the fused kernel has its
+``chunk_fn`` signature and the streaming kernel its per-sweep
+``step_fn`` signature.
 """
 from __future__ import annotations
 

@@ -1,88 +1,39 @@
-"""Jitted wrappers selecting the diffusion-sweep implementation.
+"""Stage-2 dispatch: the S-sweep ``chunk_fn`` the planner runs.
 
-``diffusion_sweep`` matches the per-sweep ``step_fn`` signature expected by
-``core.virtual_lb.virtual_balance``; ``diffusion_nsweeps`` matches the
-fused S-sweep ``chunk_fn`` signature (the production planning path).
+``diffusion_nsweeps`` matches the fused S-sweep ``chunk_fn`` signature of
+``core.virtual_lb.virtual_balance`` (the production planning path).
 
-Implementation selection (``sweep_impl``):
-
-  * ``"fused"``     — TPU, working set within :data:`FUSED_VMEM_BUDGET`:
-                      the fused multi-sweep kernel (tables loaded to VMEM
-                      once per S-sweep block, push/recv fused, flow +
-                      residual on-chip).
-  * ``"streaming"`` — TPU, tables too large for VMEM: the two-pass
-                      streaming kernel per sweep, wrapped in the shared
-                      masked chunk loop.
-  * ``"reference"`` — CPU/GPU: the pure-jnp chunk (XLA-compiled; Pallas
-                      interpret mode is Python-slow and numerically
-                      identical, so it is reserved for the kernel tests).
+Implementation selection (``sweep_impl``): every backend runs the
+``"reference"`` chunk, the pure-jnp S-sweep loop compiled by XLA.  The
+rule: the sweep is a gather over an arbitrary neighbor table
+(``x[nbr]``, ``push[nbr, rev]``), and Mosaic (the Pallas TPU compiler)
+lowers neither that gather nor a ``dynamic_slice`` of a loaded ref, so
+neither Pallas diffusion kernel compiles for a TPU.  Both kernels
+(``kernel.py``: fused multi-sweep and streaming two-pass) stay, with
+their interpret-mode parity tests, as the starting point for a
+gather-free rewrite; nothing dispatches to them.
 """
 from __future__ import annotations
 
 from repro.distributed import compat
-from repro.kernels import on_tpu
-from repro.kernels.diffusion.kernel import (
-    diffusion_nsweeps_pallas,
-    diffusion_sweep_pallas,
-)
-from repro.kernels.diffusion.ref import diffusion_sweep_ref
 from repro.core.virtual_lb import reference_nsweeps
-
-# VMEM working-set budget for the fused kernel (bytes).  ~16 MB per core;
-# half is left for double-buffered pipelining headroom and the compiler.
-FUSED_VMEM_BUDGET = 8 * 1024 * 1024
-
-
-def fused_vmem_bytes(P: int, K: int) -> int:
-    """Fused-kernel VMEM working set for a (P, K) problem.
-
-    Tables: nbr + rev (i32) and mask (i8) — (4+4+1)·P·K; carried state:
-    x/own vectors and the flow accumulator — 4·P·(K+2); per-sweep
-    intermediates: push, recv, and the (P, K+1) residual scratch —
-    ≈ 4·P·(3K+2).
-    """
-    return P * K * 9 + 4 * P * (K + 2) + 4 * P * (3 * K + 2)
 
 
 def sweep_impl(P: int, K: int) -> str:
-    """Which implementation ``diffusion_nsweeps`` selects for (P, K)."""
-    if not on_tpu():
-        return "reference"
-    if fused_vmem_bytes(P, K) <= FUSED_VMEM_BUDGET:
-        return "fused"
-    return "streaming"
-
-
-def diffusion_sweep(x, own, nbr_idx, nbr_mask, rev, alpha, single_hop=True):
-    return diffusion_sweep_pallas(
-        x, own, nbr_idx, nbr_mask, rev, alpha, single_hop,
-        interpret=not on_tpu(),
-    )
-
-
-def diffusion_sweep_reference(x, own, nbr_idx, nbr_mask, rev, alpha,
-                              single_hop=True):
-    return diffusion_sweep_ref(x, own, nbr_idx, nbr_mask, rev, alpha,
-                               single_hop)
+    """Which implementation ``diffusion_nsweeps`` selects for (P, K):
+    ``"reference"`` on every backend (see the module docstring)."""
+    del P, K
+    return "reference"
 
 
 def diffusion_nsweeps(x, own, flow, it, res, stall, nbr_idx, nbr_mask, rev,
                       alpha, *, n_sweeps: int, single_hop: bool, tol,
                       max_iters):
-    """Fused S-sweep block (``chunk_fn`` for ``virtual_balance``).
-
-    Auto-selects per :func:`sweep_impl`; all three paths are bit-for-bit
-    identical (shared ``core.virtual_lb.sweep_chunk_body``).
-    """
+    """S-sweep block (``chunk_fn`` for ``virtual_balance``), dispatched
+    per :func:`sweep_impl`."""
     impl = sweep_impl(*nbr_idx.shape)
     with compat.named_scope(f"kernel/diffusion-nsweeps-{impl}"):
-        if impl == "fused":
-            return diffusion_nsweeps_pallas(
-                x, own, flow, it, res, stall, nbr_idx, nbr_mask, rev,
-                alpha, n_sweeps=n_sweeps, single_hop=single_hop, tol=tol,
-                max_iters=max_iters)
-        step_fn = diffusion_sweep if impl == "streaming" else None
         return reference_nsweeps(
             x, own, flow, it, res, stall, nbr_idx, nbr_mask, rev, alpha,
             n_sweeps=n_sweeps, single_hop=single_hop, tol=tol,
-            max_iters=max_iters, step_fn=step_fn)
+            max_iters=max_iters)

@@ -1,15 +1,7 @@
-"""Pure-jnp oracle for the diffusion sweep kernel.
-
-This is exactly the reference implementation the balancer uses by default
-(core/virtual_lb.py); re-exported here so the kernel test sweep has a single
-canonical oracle path.
+"""Pure-jnp oracle for the diffusion kernels: exactly the chunk the
+balancer runs (core/virtual_lb.py), in the fused kernel's signature.
 """
-from repro.core.virtual_lb import reference_nsweeps, reference_sweep
-
-
-def diffusion_sweep_ref(x, own, nbr_idx, nbr_mask, rev, alpha,
-                        single_hop: bool = True):
-    return reference_sweep(x, own, nbr_idx, nbr_mask, rev, alpha, single_hop)
+from repro.core.virtual_lb import reference_nsweeps
 
 
 def diffusion_nsweeps_ref(x, own, flow, it, res, stall, nbr_idx, nbr_mask,

@@ -36,8 +36,8 @@ from repro.kernels.migrate.ref import bucket_ranks_ref, scatter_dest_ref
 
 import jax.numpy as jnp
 
-# VMEM working-set budget for the fused kernel (bytes); same headroom
-# convention as diffusion's FUSED_VMEM_BUDGET.
+# VMEM working-set budget for the fused kernel (bytes): half of the
+# ~16 MB per core, leaving the rest for pipelining and the compiler.
 MIGRATE_VMEM_BUDGET = 8 * 1024 * 1024
 
 # f32-exact slot arithmetic on the MXU bounds n (destinations are

@@ -1,4 +1,4 @@
-"""Jitted wrapper: Pallas on TPU, interpret-mode Pallas or oracle on CPU."""
+"""Jitted wrapper: the Pallas kernel on TPU, the jnp oracle elsewhere."""
 from __future__ import annotations
 
 from repro.distributed import compat
@@ -12,8 +12,8 @@ def pic_push(grid_q, x, y, vx, vy, q, *, L, dt=1.0, mass=1.0,
     """Advance particles one step.  Returns (x, y, vx, vy).
 
     ``use_kernel=None`` auto-selects: native Pallas on TPU; the jnp oracle on
-    CPU (interpret mode is Python-slow for large N — the oracle is
-    numerically identical, see tests/test_kernels.py).
+    CPU (interpret mode is Python-slow for large N — the oracle agrees to
+    f32 rounding, see tests/test_kernels.py).
     """
     if use_kernel is None:
         use_kernel = on_tpu()

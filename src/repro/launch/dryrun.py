@@ -1,8 +1,11 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=512"
+                           ).strip()
 # ^ MUST precede any jax import (device count locks at first init).  512
 # placeholder host devices back both production meshes; dry-run only — tests
-# and benchmarks see the real single device.
+# and benchmarks see the real single device.  Appended, so flags the caller
+# set survive.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 

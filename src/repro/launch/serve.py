@@ -86,6 +86,9 @@ def main():
                     help="wrap the run in jax.profiler.trace(DIR)")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     if args.fleet_replay > 0:
         fleet_replay(args)
         return

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch
 from repro.distributed import compat
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.models.params import init_params
 from repro.obs import metrics as obs_metrics
@@ -198,6 +199,7 @@ def main():
     ap.add_argument("--profile-dir", default=None,
                     help="wrap the train loop in jax.profiler.trace(DIR)")
     args = ap.parse_args()
+    compile_cache.enable()
     cfg = RunConfig(arch=args.arch, reduced=not args.full, steps=args.steps,
                     seq_len=args.seq_len, global_batch=args.batch,
                     lr=args.lr, ckpt_dir=args.ckpt_dir, remat=args.remat,

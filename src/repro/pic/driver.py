@@ -83,7 +83,7 @@ class PICConfig:
     # sweeps per fused diffusion block inside the planner (stage 2); None
     # keeps the engine default.  Plumbed into the diff-* strategies only —
     # the scanned path's lax.cond-gated planning then runs the chunked
-    # virtual-LB loop (kernels/diffusion fused kernel on TPU).
+    # virtual-LB loop (kernels/diffusion/ops.py).
     sweep_chunk: Optional[int] = None
     # two-level placement (paper §III.D): when set, every step also
     # records max/avg particles per *global PE* ((num_pes × T) threads,

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P_
 
-from repro.distributed import compat  # noqa: F401  (installs jax.shard_map)
+from repro.distributed import compat
 from repro.kernels import migrate as mig_ops
 
 AXIS = "mig"

@@ -215,10 +215,21 @@ def run_series_sharded(initial, evolve, **kwargs):
 # ------------------------------------------------------------- host loop --
 
 
+def _compiled_evolve(evolve):
+    """``evolve`` compiled as the scan body compiles it, step index
+    traced.  Op-by-op dispatch rounds unfused intermediates differently,
+    and an ulp in one load can flip a planner tie, so an eager evolve
+    would make the host loop replay another workload than the scan."""
+    jitted = jax.jit(evolve)
+    return lambda p, t: jitted(p, jnp.int32(t))
+
+
 def _run_series_host(initial, evolve, *, steps, lb_every, strategy,
                      strategy_kwargs, threads_per_node=None,
                      trig=None, tel=None) -> SeriesResult:
     trig = trig or rt_triggers.resolve(None, lb_every=lb_every)
+    if getattr(evolve, "jittable", False):
+        evolve = _compiled_evolve(evolve)
     t_start = time.perf_counter()
     problem = initial
     ma, ei, mig, tma = [], [], [], []

@@ -173,6 +173,22 @@ def test_run_series_scanned_matches_host_loop():
     np.testing.assert_allclose(host.migrations, scan.migrations, atol=1e-6)
 
 
+def test_run_series_host_loop_replays_the_scanned_workload():
+    """At P=256 an op-by-op (unfused) evolve rounds some loads an ulp
+    away from the scan's compiled evolve, which flips a planner tie at
+    the first fire; the host loop must compile evolve the same way and
+    replay the scanned trajectory exactly."""
+    problem, evolve = scenarios.get("stencil-wave").instantiate(
+        grid=128, num_nodes=256)
+    kw = dict(steps=12, lb_every=10, strategy="diff-comm")
+    host = simulator.run_series(problem, evolve, scan=False, **kw)
+    scan = simulator.run_series(problem, evolve, scan=True, **kw)
+    assert host.lb_fired.sum() == 1
+    np.testing.assert_array_equal(host.final_assignment,
+                                  scan.final_assignment)
+    np.testing.assert_array_equal(host.migrations, scan.migrations)
+
+
 def test_run_series_none_strategy_scans():
     problem, evolve = scenarios.get("stencil-wave").instantiate(
         grid=8, num_nodes=4)

@@ -90,17 +90,26 @@ def test_nsweeps_kernel_bit_for_bit_vs_iterated_reference(P, K, S,
     """The fused S-sweep kernel must equal S iterated reference sweeps
     *bit-for-bit* (interpret mode): same values, not just close ones —
     the chunked loop is a compilation strategy, not a different scheme.
-    tol=-1 keeps every sweep active so all S sweeps actually run."""
+    tol=-1 keeps every sweep active so all S sweeps actually run.
+
+    The oracle is the *jitted* sweep, as every production path runs it.
+    Eager op-by-op dispatch rounds each intermediate to f32, whereas a
+    compiled sweep may fuse the single-hop rescale ``push * scale`` into
+    the ``push - recv`` flow update as one fused multiply-subtract (one
+    rounding): that moves ``flow`` by an ulp between eager and compiled
+    execution of the *same* jnp code, so eager dispatch states no
+    contract a compiled kernel can hold."""
     x, own, flow, res0, nbr, mask, rev = _nsweeps_args(P, K)
     alpha = 1.0 / (K + 1.0)
     got = diffusion_nsweeps_pallas(
         x, own, flow, jnp.int32(0), res0, jnp.int32(0), nbr, mask, rev,
         alpha, n_sweeps=S, single_hop=single_hop, tol=-1.0,
         max_iters=10 ** 6, interpret=True)
+    sweep = jax.jit(reference_sweep, static_argnums=6)
     xs, os_, fl = x, own, flow
     for _ in range(S):
-        xs, os_, df = reference_sweep(xs, os_, nbr, mask, rev,
-                                      jnp.float32(alpha), single_hop)
+        xs, os_, df = sweep(xs, os_, nbr, mask, rev, jnp.float32(alpha),
+                            single_hop)
         fl = fl + df
     for a, b in zip(got[:3], (xs, os_, fl)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -146,20 +155,11 @@ def test_nsweeps_kernel_resumes_mid_convergence():
 
 
 def test_sweep_impl_selection_rule():
-    """Non-TPU backends take the compiled reference chunk; the documented
-    VMEM budget splits fused vs streaming on TPU."""
-    from repro.kernels import on_tpu
-
-    small, huge = (4096, 8), (1_000_000, 8)
-    if on_tpu():
-        assert diffusion_ops.sweep_impl(*small) == "fused"
-        assert diffusion_ops.sweep_impl(*huge) == "streaming"
-    else:
-        assert diffusion_ops.sweep_impl(*small) == "reference"
-        assert diffusion_ops.sweep_impl(*huge) == "reference"
-    assert (diffusion_ops.fused_vmem_bytes(*small)
-            <= diffusion_ops.FUSED_VMEM_BUDGET
-            < diffusion_ops.fused_vmem_bytes(*huge))
+    """Every backend, TPU included, takes the XLA-compiled reference
+    chunk at every size: Mosaic lowers neither Pallas diffusion kernel's
+    neighbor gather, so no shape selects one."""
+    for shape in ((64, 4), (4096, 8), (1024, 8), (1_000_000, 8)):
+        assert diffusion_ops.sweep_impl(*shape) == "reference"
 
 
 # --------------------------------------------------------------- histogram --
