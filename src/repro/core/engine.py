@@ -47,7 +47,15 @@ from repro.core import baselines, comm_graph, hierarchical
 from repro.core import neighbor_selection as ns
 from repro.core import object_selection as osel
 from repro.core import virtual_lb as vlb
+from repro.distributed import compat
 from repro.kernels.diffusion import ops as diffusion_ops
+from repro.obs import metrics as obs_metrics
+
+# host counters of the eager rebalance request (Strategy.run, eager_plan),
+# resolved once so the registry's lock stays off the request path
+_PLAN_REQUESTS = obs_metrics.counter("lb.plan.requests")
+_PLAN_HOST_READS = obs_metrics.counter("lb.plan.host_reads")
+_PLAN_STATS_NS = obs_metrics.counter("lb.plan.stats_ns")
 
 
 class PlanStats(NamedTuple):
@@ -304,33 +312,74 @@ class LBEngine:
         return eager_plan(self, problem, f"diff-{self.variant}")
 
 
+def _request_meta(strategy_name: str) -> Dict:
+    """Count one eager rebalance request; the metadata of its spans."""
+    return dict(request=int(_PLAN_REQUESTS.inc()), strategy=strategy_name)
+
+
+def _host_tail(meta: Dict, assignment, stats: Optional[PlanStats] = None,
+               thread=None):
+    """The host end of an eager rebalance request: fetch the assignment
+    (and ``thread``) under the span ``lb/plan/fetch``, then read the
+    ``PlanStats`` scalars under ``lb/plan/stats`` (``stats=None`` reads
+    none), their host ns counted on ``lb.plan.stats_ns``.  Every blocking
+    device-to-host read counts on ``lb.plan.host_reads``.  Returns
+    ``(assignment, thread, fetched_at, stat_info)``: ``fetched_at`` is
+    the ``perf_counter`` time the fetch ended, ``stat_info`` the
+    statistics of the legacy info dict."""
+    reads = 0
+    with compat.trace_annotation("lb/plan/fetch", **meta):
+        if thread is not None:
+            reads += isinstance(thread, jax.Array)
+            thread = np.asarray(jax.device_get(thread))
+        reads += isinstance(assignment, jax.Array)
+        assignment = np.asarray(jax.device_get(assignment))
+    fetched_at = time.perf_counter()
+    stat_info = {}
+    if stats is not None:
+        with compat.trace_annotation("lb/plan/stats", **meta):
+            t = time.perf_counter_ns()
+            reads += sum(isinstance(v, jax.Array) for v in stats)
+            stat_info = dict(
+                protocol_rounds=int(stats.protocol_rounds),
+                mean_degree=float(stats.mean_degree),
+                diffusion_iters=int(stats.diffusion_iters),
+                diffusion_residual=float(stats.diffusion_residual),
+                unrealized_flow=float(stats.unrealized_flow),
+            )
+            _PLAN_STATS_NS.inc(time.perf_counter_ns() - t)
+    _PLAN_HOST_READS.inc(reads)
+    return assignment, thread, fetched_at, stat_info
+
+
 def eager_plan(eng, problem, strategy_name: str,
                extra_info: Optional[Dict] = None):
     """Shared eager planning body (``LBEngine`` and the mesh-sharded
     ``distributed.lb_shard.ShardedLBEngine``): jitted dispatch — the
     two-level variant when ``threads_per_node`` is configured — one
-    device transfer, wall-clock timing, and the legacy info dict."""
+    device transfer, wall-clock timing, and the legacy info dict.  The
+    request is traced as the spans ``lb/plan`` > ``dispatch``, ``fetch``,
+    ``stats`` (see :meth:`Strategy.run`)."""
     from repro.core.api import LBPlan  # local import: api imports us
 
-    t0 = time.perf_counter()
-    thread = None
-    if eng.threads_per_node:
-        assignment, thread, stats = eng._jitted_hier(problem)
-        thread = np.asarray(jax.device_get(thread))
-    else:
-        assignment, stats = eng._jitted(problem)
-    assignment = np.asarray(jax.device_get(assignment))
-    info = dict(
-        strategy=strategy_name,
-        k=eng.k,
-        **(extra_info or {}),
-        protocol_rounds=int(stats.protocol_rounds),
-        mean_degree=float(stats.mean_degree),
-        diffusion_iters=int(stats.diffusion_iters),
-        diffusion_residual=float(stats.diffusion_residual),
-        unrealized_flow=float(stats.unrealized_flow),
-        plan_seconds=time.perf_counter() - t0,
-    )
+    meta = _request_meta(strategy_name)
+    with compat.trace_annotation("lb/plan", **meta):
+        t0 = time.perf_counter()
+        thread = None
+        with compat.trace_annotation("lb/plan/dispatch", **meta):
+            if eng.threads_per_node:
+                assignment, thread, stats = eng._jitted_hier(problem)
+            else:
+                assignment, stats = eng._jitted(problem)
+        assignment, thread, _, stat_info = _host_tail(
+            meta, assignment, stats, thread)
+        info = dict(
+            strategy=strategy_name,
+            k=eng.k,
+            **(extra_info or {}),
+            **stat_info,
+            plan_seconds=time.perf_counter() - t0,
+        )
     if thread is not None:
         info.update(thread=thread, threads_per_node=eng.threads_per_node)
     return LBPlan(assignment, info)
@@ -434,25 +483,29 @@ class Strategy:
         return lambda problem: self.plan_fn(problem, **p)
 
     def run(self, problem: comm_graph.LBProblem, **overrides):
-        """Eager execution returning the legacy ``LBPlan``."""
+        """Eager execution returning the legacy ``LBPlan``.
+
+        One rebalance request: counted on ``lb.plan.requests`` and traced
+        as the host span ``lb/plan`` enclosing ``lb/plan/dispatch`` (the
+        ``plan_fn`` call until it returns), ``lb/plan/fetch`` (the
+        assignment's transfer) and ``lb/plan/stats`` (the ``PlanStats``
+        reads), all with the metadata ``request`` and ``strategy``."""
         from repro.core.api import LBPlan  # local import: api imports us
 
-        t0 = time.perf_counter()
-        params = self.params(**overrides)
-        assignment, stats = self.plan_fn(problem, **params)
-        assignment = np.asarray(jax.device_get(assignment))
-        info = dict(strategy=self.name,
-                    plan_seconds=time.perf_counter() - t0,
-                    **{k: v for k, v in params.items()
-                       if isinstance(v, (int, float, bool, str))})
-        if self.name.startswith("diff"):  # incl. the sharded variants
-            info.update(
-                protocol_rounds=int(stats.protocol_rounds),
-                mean_degree=float(stats.mean_degree),
-                diffusion_iters=int(stats.diffusion_iters),
-                diffusion_residual=float(stats.diffusion_residual),
-                unrealized_flow=float(stats.unrealized_flow),
-            )
+        meta = _request_meta(self.name)
+        with compat.trace_annotation("lb/plan", **meta):
+            t0 = time.perf_counter()
+            params = self.params(**overrides)
+            with compat.trace_annotation("lb/plan/dispatch", **meta):
+                assignment, stats = self.plan_fn(problem, **params)
+            diff = self.name.startswith("diff")  # incl. the sharded ones
+            assignment, _, fetched_at, stat_info = _host_tail(
+                meta, assignment, stats if diff else None)
+            info = dict(strategy=self.name,
+                        plan_seconds=fetched_at - t0,
+                        **{k: v for k, v in params.items()
+                           if isinstance(v, (int, float, bool, str))})
+            info.update(stat_info)
         return LBPlan(assignment, info)
 
 

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import comm_graph
+from repro.distributed import compat
 
 NEG = jnp.float32(-1e30)
 
@@ -102,43 +103,51 @@ def select_objects(
         target = jnp.where(budget > 0, nbr_idx[node_ids, slot], -1)  # (P,)
 
         # Ordering metric, per the variant.
-        if metric == "comm":
-            # Bytes each object exchanges with its node's phase target —
-            # the active column of comm_graph.object_node_bytes, computed
-            # directly (one segment-sum over E per direction instead of
-            # the full (N, K) table; the "peers update their patterns"
-            # rule is preserved because this reruns on the phase's
-            # current assignment).
-            tgt_obj = target[assignment]                        # (N,)
+        with compat.named_scope("score"):
+            if metric == "comm":
+                # Bytes each object exchanges with its node's phase
+                # target — the active column of
+                # comm_graph.object_node_bytes, computed directly (one
+                # segment-sum over E per direction instead of the full
+                # (N, K) table; the "peers update their patterns" rule is
+                # preserved because this reruns on the phase's current
+                # assignment).
+                tgt_obj = target[assignment]                    # (N,)
 
-            def dir_score(a, b):
-                hit = (assignment[b] == tgt_obj[a]) & (tgt_obj[a] >= 0)
-                return jax.ops.segment_sum(
-                    jnp.where(hit, e_w, 0.0), a, num_segments=N)
+                def dir_score(a, b):
+                    hit = ((assignment[b] == tgt_obj[a])
+                           & (tgt_obj[a] >= 0))
+                    return jax.ops.segment_sum(
+                        jnp.where(hit, e_w, 0.0), a, num_segments=N)
 
-            score = dir_score(e_src, e_dst) + dir_score(e_dst, e_src)
-            if score_psum_axis is not None:
-                score = jax.lax.psum(score, score_psum_axis)
-        elif metric == "coord":
-            assert problem.coords is not None, "coordinate variant needs coords"
-            cent = _centroids(problem.coords, assignment, P)
-            tgt = jnp.where(target >= 0, target, 0)[assignment]  # (N,)
-            d2 = jnp.sum((problem.coords - cent[tgt]) ** 2, axis=-1)
-            score = -d2                                          # closest first
-        else:
-            raise ValueError(f"unknown metric {metric!r}")
+                score = dir_score(e_src, e_dst) + dir_score(e_dst, e_src)
+                if score_psum_axis is not None:
+                    score = jax.lax.psum(score, score_psum_axis)
+            elif metric == "coord":
+                assert problem.coords is not None, \
+                    "coordinate variant needs coords"
+                cent = _centroids(problem.coords, assignment, P)
+                tgt = jnp.where(target >= 0, target, 0)[assignment]
+                d2 = jnp.sum((problem.coords - cent[tgt]) ** 2, axis=-1)
+                score = -d2                              # closest first
+            else:
+                raise ValueError(f"unknown metric {metric!r}")
 
-        eligible = ~moved & (target[assignment] >= 0)
-        take = _segmented_take_while(assignment, score, loads, eligible, budget)
+        # Take while under budget, then ship.
+        with compat.named_scope("take"):
+            eligible = ~moved & (target[assignment] >= 0)
+            take = _segmented_take_while(assignment, score, loads,
+                                         eligible, budget)
 
-        shipped = jax.ops.segment_sum(
-            jnp.where(take, loads, 0.0), assignment, num_segments=P
-        )
-        new_owner = jnp.where(target >= 0, target, 0)[assignment]
-        assignment = jnp.where(take, new_owner, assignment)
-        moved = moved | take
-        realized = realized.at[node_ids, slot].add(shipped)
-        send = send.at[node_ids, slot].set(0.0)  # slot done (shipped or not)
+            shipped = jax.ops.segment_sum(
+                jnp.where(take, loads, 0.0), assignment, num_segments=P
+            )
+            new_owner = jnp.where(target >= 0, target, 0)[assignment]
+            assignment = jnp.where(take, new_owner, assignment)
+            moved = moved | take
+            realized = realized.at[node_ids, slot].add(shipped)
+            # slot done (shipped or not)
+            send = send.at[node_ids, slot].set(0.0)
 
     residual = jnp.where(nbr_mask, jnp.maximum(flows, 0.0), 0.0) - realized
     return SelectionResult(assignment, moved, realized, residual)

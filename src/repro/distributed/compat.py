@@ -20,9 +20,15 @@ def named_scope(name: str):
     return jax.named_scope(name)
 
 
-def trace_annotation(name: str):
-    """Host-side profiler region (``jax.profiler.TraceAnnotation``)."""
-    return jax.profiler.TraceAnnotation(name)
+def trace_annotation(name: str, **meta):
+    """Host-side profiler span (``jax.profiler.TraceAnnotation``).
+
+    Records ``name`` on the calling thread's line of a running profiler
+    trace, on the device events' clock; each keyword of ``meta`` arrives
+    as a stat of the event (``request=3``).  With no profiler running,
+    entering and leaving it records nothing.  ``core.engine`` opens the
+    rebalance request's ``lb/plan`` spans with it."""
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 def profiler_trace(log_dir):

@@ -85,6 +85,9 @@ class MetricsRegistry:
             return dict(sorted(out.items()))
 
     def reset(self) -> None:
+        """Forget every name.  A counter resolved before the reset (such
+        as ``core.engine``'s ``lb.plan.*``, resolved at import) keeps
+        counting but is no longer in the snapshot."""
         with self._lock:
             self._counters.clear()
             self._gauges.clear()

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from repro.core import api as core_api
 from repro.core import engine as core_engine
 from repro.core import hierarchical
+from repro.distributed import compat
 from repro.obs import telemetry as obs_telemetry
 from repro.kernels.histogram.ops import histogram
 from repro.kernels.pic_push.ops import pic_push
@@ -283,13 +284,16 @@ def _chunk_runner(
             x, y, vx, vy, q, chare_id, assignment, perm, tstate = carry
         xn, yn, vxn, vyn = pic_push(grid_q, x, y, vx, vy, q, L=L,
                                     use_kernel=use_kernel)
-        new_chare = ch.chare_of_device(xn, yn, L, cx, cy)
-        # particle handoffs: chare changed → bytes move; PE boundary → ext
-        moved = new_chare != chare_id
-        src_pe = assignment[chare_id]
-        dst_pe = assignment[new_chare]
-        ext = (moved & (src_pe != dst_pe)).sum().astype(jnp.float32) * bpp
-        intra = (moved & (src_pe == dst_pe)).sum().astype(jnp.float32) * bpp
+        with compat.named_scope("replay/handoff"):
+            new_chare = ch.chare_of_device(xn, yn, L, cx, cy)
+            # particle handoffs: chare changed → bytes move; PE boundary → ext
+            moved = new_chare != chare_id
+            src_pe = assignment[chare_id]
+            dst_pe = assignment[new_chare]
+            ext = ((moved & (src_pe != dst_pe)).sum().astype(jnp.float32)
+                   * bpp)
+            intra = ((moved & (src_pe == dst_pe)).sum()
+                     .astype(jnp.float32) * bpp)
 
         loads = histogram(new_chare, jnp.ones_like(xn), C=n_chares,
                           use_kernel=use_kernel)
@@ -323,8 +327,9 @@ def _chunk_runner(
             # execute the plan: relocate particle payload between the
             # PE-owned slot regions (bucketed gather — runtime.migrate);
             # migrated_bytes is measured from this exchange, not modeled
-            owner_old = jnp.take(assignment, new_chare)
-            owner_new = jnp.take(new_assignment, new_chare)
+            with compat.named_scope("replay/owners"):
+                owner_old = jnp.take(assignment, new_chare)
+                owner_new = jnp.take(new_assignment, new_chare)
 
             def do_move(args):
                 outs, man = rt_migrate.build_and_apply(

@@ -199,9 +199,12 @@ def build_and_apply(owner_old, owner_new, arrays: Sequence, *,
     destination scatters were measured slower than scatter-once + gather
     on CPU XLA (scatters cost ~25× a gather there) and scatters
     serialize on TPU, so the gather form wins for any payload count.
-    Layout is bit-for-bit the ``method="sort"`` result."""
-    man = build_manifest(owner_old, owner_new, num_nodes, method=method)
-    return apply_manifest(man, *arrays), man
+    Layout is bit-for-bit the ``method="sort"`` result.  Its ops carry
+    the scope ``exchange/migrate`` wherever it is traced: the scanned
+    replays' fired branch and the eager :func:`migrate` alike."""
+    with compat.named_scope("exchange/migrate"):
+        man = build_manifest(owner_old, owner_new, num_nodes, method=method)
+        return apply_manifest(man, *arrays), man
 
 
 def inverse_permutation(order) -> jax.Array:
@@ -236,11 +239,9 @@ def migrate(owner_old, owner_new, arrays: Sequence, *, num_nodes: int,
     strict — spill semantics belong to the in-scan exchanges)."""
     if donate is None:
         donate = jax.default_backend() != "cpu"
-    with compat.named_scope("exchange/migrate"):
-        out, man = _migrate_exec(int(num_nodes), bool(donate),
-                                 str(method))(
-            jnp.asarray(owner_old, jnp.int32),
-            jnp.asarray(owner_new, jnp.int32), tuple(arrays))
+    out, man = _migrate_exec(int(num_nodes), bool(donate), str(method))(
+        jnp.asarray(owner_old, jnp.int32),
+        jnp.asarray(owner_new, jnp.int32), tuple(arrays))
     if capacity is not None:
         counts = np.diff(np.asarray(man.offsets))
         if (counts > int(capacity)).any():
