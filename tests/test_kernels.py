@@ -1,8 +1,11 @@
 """Pallas kernels vs pure-jnp oracles, interpret mode, shape/dtype sweeps."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 from tests._hyp import given, settings, st
 
 from repro.core.virtual_lb import (
@@ -18,7 +21,7 @@ from repro.kernels.diffusion.kernel import (
 from repro.kernels.diffusion.ref import diffusion_nsweeps_ref
 from repro.kernels.histogram.kernel import histogram_pallas
 from repro.kernels.histogram.ref import histogram_ref
-from repro.kernels.pic_push.kernel import pic_push_pallas
+from repro.kernels.pic_push.kernel import corner_charges, pic_push_pallas
 from repro.kernels.pic_push.ref import pic_push_ref
 from repro.pic.grid import alternating_grid
 from repro.pic.particles import initialize
@@ -198,6 +201,104 @@ def test_pic_push_matches_ref(L, N, block_n):
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+def _four_takes(grid_q, x, y, L):
+    """The four scalar corner gathers the push used to make, one per
+    corner, each wrapping its own row and column."""
+    i0 = jnp.floor(x).astype(jnp.int32)
+    j0 = jnp.floor(y).astype(jnp.int32)
+    gf = grid_q.astype(jnp.float32).reshape(-1)
+    return tuple(
+        jnp.take(gf, jnp.mod(i0 + di, L) * L + jnp.mod(j0 + dj, L),
+                 mode="clip")
+        for di, dj in ((0, 0), (0, 1), (1, 0), (1, 1)))
+
+
+@pytest.mark.parametrize("L", [37, 128, 1000])
+def test_corner_charges_match_four_scalar_gathers(L):
+    rng = np.random.default_rng(L)
+    g = jnp.asarray(rng.standard_normal((L, L)), jnp.float32)
+    assert not np.array_equal(np.asarray(g), np.asarray(g).T)
+    # 0, just below L, L itself (floor == L), the last row and column
+    edge = np.float32([0, L - 1e-4, L, L - 1, L - 0.5, np.nextafter(L, 0)])
+    ex, ey = np.meshgrid(edge, edge)
+    x = np.concatenate([rng.uniform(0, L, 4000), ex.ravel()])
+    y = np.concatenate([rng.uniform(0, L, 4000), ey.ravel()])
+    x, y = jnp.asarray(x, jnp.float32), jnp.asarray(y, jnp.float32)
+    assert (np.floor(np.asarray(x)) == L).any()
+    got = jax.jit(corner_charges, static_argnames="L")(g, x, y, L=L)
+    assert got.shape == (4, x.shape[0])
+    want = np.stack([np.asarray(a) for a in _four_takes(g, x, y, L)])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _four_input_kernel(x_ref, y_ref, vx_ref, vy_ref, q_ref,
+                       q00_ref, q01_ref, q10_ref, q11_ref,
+                       xo_ref, yo_ref, vxo_ref, vyo_ref, *, L, dt, mass):
+    """The push kernel as it read four (N,) corner-charge inputs."""
+    x, y = x_ref[...], y_ref[...]
+    vx, vy = vx_ref[...], vy_ref[...]
+    q = q_ref[...]
+    qcs = (q00_ref[...], q01_ref[...], q10_ref[...], q11_ref[...])
+    i0 = jnp.floor(x).astype(jnp.int32)
+    j0 = jnp.floor(y).astype(jnp.int32)
+    fx = jnp.zeros_like(x)
+    fy = jnp.zeros_like(y)
+    for (di, dj), qc in zip(((0, 0), (0, 1), (1, 0), (1, 1)), qcs):
+        dx = x - (i0 + di).astype(x.dtype)
+        dy = y - (j0 + dj).astype(y.dtype)
+        r2 = dx * dx + dy * dy
+        r = jnp.sqrt(r2)
+        f = q * qc / jnp.maximum(r2, 1e-12)
+        fx = fx + f * dx / jnp.maximum(r, 1e-6)
+        fy = fy + f * dy / jnp.maximum(r, 1e-6)
+    ax = fx / mass
+    ay = fy / mass
+    xn = x + vx * dt + 0.5 * ax * dt * dt
+    yn = y + vy * dt + 0.5 * ay * dt * dt
+    xo_ref[...] = jnp.mod(xn, jnp.float32(L))
+    yo_ref[...] = jnp.mod(yn, jnp.float32(L))
+    vxo_ref[...] = vx + ax * dt
+    vyo_ref[...] = vy + ay * dt
+
+
+@functools.partial(jax.jit, static_argnames=("L", "block_n"))
+def _push_four_inputs(grid_q, x, y, vx, vy, q, *, L, block_n):
+    """Four scalar gathers, each corner streamed as its own input."""
+    N = x.shape[0]
+    Np = -(-N // block_n) * block_n
+
+    def pad(a):
+        return jnp.pad(a.astype(jnp.float32), (0, Np - N))
+
+    x = x.astype(jnp.float32)
+    y = y.astype(jnp.float32)
+    blk = pl.BlockSpec((block_n,), lambda i: (i,))
+    outs = pl.pallas_call(
+        functools.partial(_four_input_kernel, L=L, dt=1.0, mass=1.0),
+        grid=(Np // block_n,),
+        in_specs=[blk] * 9,
+        out_specs=[blk] * 4,
+        out_shape=[jax.ShapeDtypeStruct((Np,), jnp.float32)] * 4,
+        interpret=True,
+    )(*map(pad, (x, y, vx, vy, q) + _four_takes(grid_q, x, y, L)))
+    return tuple(o[:N] for o in outs)
+
+
+@pytest.mark.parametrize("grid", ["prk", "random"])
+@pytest.mark.parametrize("L,N,block_n", [(32, 100, 64), (64, 1000, 256),
+                                         (128, 333, 512)])
+def test_pic_push_matches_four_input_path(L, N, block_n, grid):
+    p = initialize("GEOMETRIC", L, N, k=1, seed=L)
+    g = jnp.asarray(alternating_grid(L) if grid == "prk" else
+                    np.random.default_rng(L).standard_normal((L, L)),
+                    jnp.float32)
+    args = tuple(map(jnp.asarray, (p.x, p.y, p.vx, p.vy, p.q)))
+    got = pic_push_pallas(g, *args, L=L, block_n=block_n, interpret=True)
+    want = _push_four_inputs(g, *args, L=L, block_n=block_n)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("mode", ["GEOMETRIC", "SINUSOIDAL", "LINEAR",
