@@ -31,6 +31,8 @@ Two entries:
     all-to-all, whose per-shard placement is the shared sort-free
     counting-scatter op (``kernels.migrate.bucket_ranks``) — to
     re-bucket the slabs into PE-owned slot regions *inside the scan*.
+    It is one chunk of the resumable entry :func:`prepare_pic`, whose
+    ``run_chunk`` advances the carried state from any absolute step.
 
 Parity contract (the reason this file exists as a *replay* subsystem and
 not just a loop around the standalone pieces): both entries are
@@ -72,7 +74,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +88,7 @@ from repro.core import engine as core_engine
 from repro.core import neighbor_selection as ns
 from repro.core import object_selection as osel
 from repro.core import virtual_lb as vlb
+from repro.obs import metrics as obs_metrics
 from repro.obs import telemetry as obs_telemetry
 from repro.runtime import migrate as rt_migrate
 from repro.runtime import resilience as rt_resilience
@@ -257,7 +260,8 @@ def _plan_step_sharded(problem: comm_graph.LBProblem, *, variant: str,
     targets them.  ``alive=None`` (the default) adds nothing to the
     trace."""
     if alive is not None:
-        problem = rt_resilience.degrade_problem(problem, alive, speed)
+        with compat.named_scope("resilience/rehome"):
+            problem = rt_resilience.degrade_problem(problem, alive, speed)
     # -- stage 1: preference assembly + handshake (replicated) ----------
     with compat.named_scope("lb-plan/stage1-neighbors"):
         if variant == "comm":
@@ -818,14 +822,45 @@ def prepare_series(
 
 # -------------------------------------------------------- PIC replay ----
 
+#: host counters of the PIC replay, bumped by :meth:`_PreparedPIC.run_chunk`
+#: from each chunk's per-step outputs once they are on the host
+_REPLAY_STEPS = obs_metrics.counter("lb.replay.steps")
+_REPLAY_FIRES = obs_metrics.counter("lb.replay.fires")
 
-def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
-                k: int, vy0: float, lb_every: int, strategy: str,
-                kw_items: tuple, bpp: float, use_kernel: Optional[bool],
-                steps: int, capacity: int,
-                threads_per_node: Optional[int], trig,
-                faults=None, on_overflow: str = "strict", tel=None):
-    """Compile-once ``shard_map`` wrapping the whole PIC replay.
+#: per-step outputs of every PIC chunk besides the (T, D) ``count``
+_PIC_YS = ("max_avg", "pe_max", "ext", "int", "moved_share",
+           "migrated_bytes", "thread_max_avg", "fired", "forced",
+           "assignment")
+
+
+class PICCarry(NamedTuple):
+    """State of the sharded PIC replay between two chunks.
+
+    The seven payload slabs are (D*capacity,) arrays sharded over the
+    mesh; shard ``d`` holds ``count[d]`` live particles at the prefix of
+    its ``capacity`` slots.  ``assignment`` (chare → PE) and the trigger
+    (and telemetry) state are replicated."""
+
+    x: jax.Array
+    y: jax.Array
+    vx: jax.Array
+    vy: jax.Array
+    q: jax.Array
+    chare: jax.Array
+    perm: jax.Array                 # particle id of each slot
+    count: jax.Array                # (D,) i32 live slots per shard
+    assignment: jax.Array           # (C,) i32
+    trigger: rt_triggers.TriggerState
+    telemetry: Optional[obs_telemetry.TelemetryState] = None
+
+
+def _make_pic_step(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
+                   k: int, vy0: float, lb_every: int, strategy: str,
+                   kw_items: tuple, bpp: float, use_kernel: Optional[bool],
+                   capacity: int, threads_per_node: Optional[int], trig,
+                   faults=None, on_overflow: str = "strict", tel=None):
+    """The per-step body of the sharded PIC replay (runs under the
+    chunk runner's ``shard_map``).
 
     Per-shard carry: the (capacity,) particle payload slabs (x, y, vx,
     vy, q, chare id, particle id) with a live-prefix count, plus the
@@ -848,7 +883,17 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
     rebalance retries them) and the per-step ``deferred`` count is
     emitted.  ``faults=None`` + strict mode is the exact pre-resilience
     trace.
-    """
+
+    Scopes: ``replay/handoff`` (chare lookup, handoff counts),
+    ``replay/psum`` (their and the histogram's ``psum``),
+    ``resilience/health`` (health projection, masked stats, stranded
+    check), ``resilience/guard`` (``validate_plan``, per-shard capacity),
+    ``replay/owners`` (old and new owner of each particle) and, inside
+    the exchange, ``exchange/ring``.
+
+    Returns ``(step, track)``; ``step`` emits a dict of per-step outputs
+    (``_PreparedPIC.run_chunk`` documents the keys) and ``track`` says
+    whether it holds ``rejected`` and ``deferred``."""
     from repro.kernels.histogram.ops import histogram
     from repro.kernels.pic_push.ops import pic_push
     from repro.pic import chares as ch
@@ -893,24 +938,24 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
              tstate) = carry
         xn, yn, vxn, vyn = pic_push(grid_q, x, y, vx, vy, q, L=L,
                                     use_kernel=use_kernel)
-        new_chare = ch.chare_of_device(xn, yn, L, cx, cy)
-        live = jnp.arange(capacity, dtype=jnp.int32) < count
-        # particle handoffs: chare changed → bytes move; PE boundary →
-        # ext.  Partial counts are integers — psum completion is exact,
-        # so the f32 byte totals match the single-device path bitwise.
-        moved = (new_chare != chare_id) & live
-        src_pe = assignment[chare_id]
-        dst_pe = assignment[new_chare]
-        ext = jax.lax.psum(
-            (moved & (src_pe != dst_pe)).sum(), ax).astype(jnp.float32) \
-            * bpp
-        intra = jax.lax.psum(
-            (moved & (src_pe == dst_pe)).sum(), ax).astype(jnp.float32) \
-            * bpp
-
-        loads = jax.lax.psum(
-            histogram(new_chare, live.astype(xn.dtype), C=n_chares,
-                      use_kernel=use_kernel), ax)
+        with compat.named_scope("replay/handoff"):
+            new_chare = ch.chare_of_device(xn, yn, L, cx, cy)
+            live = jnp.arange(capacity, dtype=jnp.int32) < count
+            # particle handoffs: chare changed → bytes move; PE boundary
+            # → ext.  Partial counts are integers — psum completion is
+            # exact, so the f32 byte totals match the single-device path
+            # bitwise.
+            moved = (new_chare != chare_id) & live
+            src_pe = assignment[chare_id]
+            dst_pe = assignment[new_chare]
+            n_ext = (moved & (src_pe != dst_pe)).sum()
+            n_int = (moved & (src_pe == dst_pe)).sum()
+        hist = histogram(new_chare, live.astype(xn.dtype), C=n_chares,
+                         use_kernel=use_kernel)
+        with compat.named_scope("replay/psum"):
+            ext = jax.lax.psum(n_ext, ax).astype(jnp.float32) * bpp
+            intra = jax.lax.psum(n_int, ax).astype(jnp.float32) * bpp
+            loads = jax.lax.psum(hist, ax)
         pe_loads = jax.ops.segment_sum(loads, assignment,
                                        num_segments=num_pes)
         pe_max = pe_loads.max()
@@ -918,14 +963,16 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
         rejected = jnp.float32(0.0)
         deferred_n = jnp.int32(0)
         health_changed = jnp.float32(0.0)
+        forced = jnp.float32(0.0)
         sweeps = jnp.float32(0.0)
         moved_n = jnp.int32(0)
 
         if lb_on:
             if resilient:
-                alive_n, speed_n = faults.node_health(t, num_pes, D)
-                mx, av, tot = rt_triggers.load_stats_masked(
-                    loads, assignment, num_pes, alive_n, speed_n)
+                with compat.named_scope("resilience/health"):
+                    alive_n, speed_n = faults.node_health(t, num_pes, D)
+                    mx, av, tot = rt_triggers.load_stats_masked(
+                        loads, assignment, num_pes, alive_n, speed_n)
             else:
                 alive_n = speed_n = None
                 mx, av, tot = rt_triggers.load_stats(loads, assignment,
@@ -934,11 +981,13 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
             if resilient:
                 # evacuate dead PEs now: fire on every health transition
                 # and while any chare is still owned by a dead PE
-                stranded = (~jnp.take(
-                    alive_n, jnp.clip(assignment, 0, num_pes - 1))).any()
-                health_changed = faults.changed_at(
-                    t, D).astype(jnp.float32)
-                do = do | faults.changed_at(t, D) | stranded
+                with compat.named_scope("resilience/health"):
+                    stranded = (~jnp.take(
+                        alive_n, jnp.clip(assignment, 0, num_pes - 1))).any()
+                    changed = faults.changed_at(t, D)
+                    health_changed = changed.astype(jnp.float32)
+                    forced = (changed | stranded).astype(jnp.float32)
+                    do = do | changed | stranded
 
             def do_plan(args):
                 loads_, assignment_ = args
@@ -962,17 +1011,18 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
                 # and in range and, in strict mode, per-shard inflow
                 # within the static slab budget (a plan that does not
                 # fit would drop payload; spill clamps instead)
-                ok = rt_resilience.validate_plan(
-                    planned, loads, num_nodes=num_pes, alive=alive_n)
-                if not spill:
-                    pe_new = jax.ops.segment_sum(
-                        loads, jnp.clip(planned, 0, num_pes - 1),
-                        num_segments=num_pes)
-                    per_shard = pe_new.reshape(D, num_pes // D).sum(1)
-                    ok = ok & (per_shard <= capacity).all()
-                adopt = do & ok
-                rejected = (do & ~ok).astype(jnp.float32)
-                new_assignment = jnp.where(adopt, planned, assignment)
+                with compat.named_scope("resilience/guard"):
+                    ok = rt_resilience.validate_plan(
+                        planned, loads, num_nodes=num_pes, alive=alive_n)
+                    if not spill:
+                        pe_new = jax.ops.segment_sum(
+                            loads, jnp.clip(planned, 0, num_pes - 1),
+                            num_segments=num_pes)
+                        per_shard = pe_new.reshape(D, num_pes // D).sum(1)
+                        ok = ok & (per_shard <= capacity).all()
+                    adopt = do & ok
+                    rejected = (do & ~ok).astype(jnp.float32)
+                    new_assignment = jnp.where(adopt, planned, assignment)
             else:
                 adopt = do
                 new_assignment = planned
@@ -984,8 +1034,9 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
             # all-to-all re-buckets the live slab prefixes into PE-owned
             # slot regions — concatenated prefixes reproduce the
             # single-device bucketed layout bit-for-bit
-            owner_old = jnp.take(assignment, new_chare)
-            owner_new = jnp.take(new_assignment, new_chare)
+            with compat.named_scope("replay/owners"):
+                owner_old = jnp.take(assignment, new_chare)
+                owner_new = jnp.take(new_assignment, new_chare)
 
             if spill:
                 def do_move(args):
@@ -1039,10 +1090,13 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
         else:
             tma = jnp.float32(0.0)
 
-        ys = (ma, pe_max, ext, intra, migf, migb, tma, fired,
-              count[None])
+        ys = dict(max_avg=ma, pe_max=pe_max, ext=ext, int=intra,
+                  moved_share=migf, migrated_bytes=migb,
+                  thread_max_avg=tma, fired=fired, forced=forced,
+                  count=count[None], assignment=assignment)
         if track:
-            ys = ys + (rejected, deferred_n.astype(jnp.float32))
+            ys.update(rejected=rejected,
+                      deferred=deferred_n.astype(jnp.float32))
         new_carry = (xn, yn, vxn, vyn, q, new_chare, assignment, perm,
                      count, tstate)
         if tel:
@@ -1058,65 +1112,250 @@ def _pic_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
             new_carry = new_carry + (obs_state,)
         return new_carry, ys
 
-    def body(x, y, vx, vy, q, chare_id, perm, count0, assignment):
-        carry = (x, y, vx, vy, q, chare_id, assignment, perm,
-                 count0[0], trig.init_state())
-        if tel:
-            carry = carry + (obs_telemetry.init_state(tel, num_pes),)
-        carry, ys = jax.lax.scan(step, carry, jnp.arange(steps))
-        (x, y, perm, count) = (carry[0], carry[1], carry[7], carry[8])
-        out = ys + (x, y, perm, count[None])
-        if tel:
-            out = out + tuple(carry[10])   # replicated ring — exits as-is
-        return out
+    return step, track
 
+
+def _pic_chunk_runner(mesh: Mesh, L: int, cx: int, cy: int, num_pes: int,
+                      k: int, vy0: float, lb_every: int, strategy: str,
+                      kw_items: tuple, bpp: float,
+                      use_kernel: Optional[bool], chunk: int,
+                      capacity: int, threads_per_node: Optional[int], trig,
+                      faults=None, on_overflow: str = "strict", tel=None):
+    """Compile-once ``shard_map`` scanning ``chunk`` PIC steps from an
+    explicit carry, the steps numbered ``t0 + arange(chunk)``.  Takes
+    ``(t0, *carry)`` (the :class:`PICCarry` fields, ``telemetry`` only
+    when on) and returns ``(carry, ys)``."""
+    step, track = _make_pic_step(
+        mesh, L, cx, cy, num_pes, k, vy0, lb_every, strategy, kw_items,
+        bpp, use_kernel, capacity, threads_per_node, trig, faults,
+        on_overflow, tel)
+    ax = mesh.axis_names[0]
+
+    def run_chunk(t0, x, y, vx, vy, q, chare_id, perm, count, assignment,
+                  tstate, *obs):
+        carry = (x, y, vx, vy, q, chare_id, assignment, perm, count[0],
+                 tstate) + obs
+        carry, ys = jax.lax.scan(step, carry, t0 + jnp.arange(chunk))
+        (x, y, vx, vy, q, chare_id, assignment, perm, count,
+         tstate) = carry[:10]
+        return ((x, y, vx, vy, q, chare_id, perm, count[None], assignment,
+                 tstate) + tuple(carry[10:])), ys
+
+    nobs = 1 if tel else 0
+    ys_specs = {name: P_() for name in _PIC_YS}
+    ys_specs["count"] = P_(None, ax)         # (T, D) per-shard counts
+    if track:
+        ys_specs.update(rejected=P_(), deferred=P_())
+    carry_specs = (P_(ax),) * 8 + (P_(),) * (2 + nobs)
     fn = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P_(ax),) * 8 + (P_(),),
-        out_specs=((P_(),) * 8               # per-step replicated metrics
-                   + (P_(None, ax),)         # per-step per-shard counts
-                   + ((P_(),) * 2 if track else ())  # rejected, deferred
-                   + (P_(ax),) * 4           # final slabs + counts
-                   + ((P_(),) * 3 if tel else ())),  # TelemetryState
+        run_chunk, mesh=mesh,
+        in_specs=(P_(),) + carry_specs,
+        out_specs=(carry_specs, ys_specs),
         check_vma=False)
     return jax.jit(fn)
 
 
-def _pad_slabs(arrays, n: int, D: int, capacity: int):
-    """Distribute (n,) buffers into (D*capacity,) per-shard slabs with
-    n/D live items at each shard's prefix."""
-    per = n // D
-    out = []
-    for a in arrays:
-        a = np.asarray(a)
-        slab = np.zeros((D, capacity), a.dtype)
-        slab[:, :per] = a.reshape(D, per)
-        out.append(jnp.asarray(slab.reshape(-1)))
-    return out
+def _slab_builder(mesh: Mesh, n: int, capacity: int, L: int, cx: int,
+                  cy: int):
+    """Compiled (n,) particles → :class:`PICCarry` payload slabs, on the
+    device: shard ``d`` keeps particles ``[d*n/D, (d+1)*n/D)`` at the
+    prefix of its ``capacity`` slots (zero padding after), with their
+    chare ids and particle ids, and its live count."""
+    from repro.pic import chares as ch
+
+    ax = mesh.axis_names[0]
+    per = n // int(np.prod(mesh.devices.shape))
+
+    def build(x, y, vx, vy, q):
+        chare = ch.chare_of_device(x, y, L, cx, cy)
+        perm = (jax.lax.axis_index(ax) * per
+                + jnp.arange(per, dtype=jnp.int32))
+        slabs = tuple(jnp.pad(a, (0, capacity - per))
+                      for a in (x, y, vx, vy, q, chare, perm))
+        return slabs + (jnp.full((1,), per, jnp.int32),)
+
+    return jax.jit(jax.shard_map(build, mesh=mesh, in_specs=(P_(ax),) * 5,
+                                 out_specs=(P_(ax),) * 8, check_vma=False))
 
 
-def run_pic_sharded(cfg, cost) -> "PICResult":  # noqa: F821
-    """Mesh-sharded scanned PIC driver (``PICConfig(sharded_replay=True)``).
+class _PreparedPIC:
+    """Chunk-driving handle over the sharded PIC replay.
 
-    The whole run — push, handoff/byte accounting, trigger, planning and
-    the executed particle exchange — is one compiled ``shard_map`` over
-    the 1-D ``"lb"`` mesh with the particle slabs row-sharded; the only
-    host contact is staging the initial slabs in and the final slabs +
-    per-step metric series out.  Bit-for-bit the single-device scanned
-    driver's ``PICResult`` (including ``final_x/final_y`` restored to
-    particle-id order).  See the module docstring for the capacity rule;
-    a ``replay_capacity`` below the largest per-shard bucket total
-    raises ``ValueError`` after the run (payload is never dropped
-    silently).
+    Built by :func:`prepare_pic`: :meth:`initial_carry` is the state at
+    step 0, :meth:`run_chunk` advances it by any number of steps from
+    any absolute step index, and :meth:`package` turns the final carry
+    and the chunks' outputs into the ``PICResult`` that
+    :func:`run_pic_sharded` (one chunk over every step) returns.  The
+    per-step program is one function whatever the chunking, so chunked
+    trajectories are bit-for-bit the one-shot run."""
 
-    ``PICConfig.faults`` injects a ``runtime.resilience.FaultSchedule``
-    into the scan (health-masked trigger/planning, forced evacuation
-    fires, guarded plan adoption) and ``PICConfig.on_overflow="spill"``
-    swaps the exchange for the admission-clamped spill ring (overflow
-    particles stay on their source shard and drain on later fires);
-    either adds the ``plan_rejected`` / ``deferred`` per-step series to
-    the result.  Defaults leave the trace bit-for-bit unchanged."""
-    from repro.kernels.histogram.ops import histogram
+    def __init__(self, *, cfg, mesh, capacity, trig, faults, on_overflow,
+                 tel, kw_items, carry0):
+        self.cfg = cfg
+        self.mesh = mesh
+        self.D = int(np.prod(mesh.devices.shape))
+        self.capacity = int(capacity)
+        self.trig = trig
+        self.faults = faults
+        self.on_overflow = on_overflow
+        self.tel = tel
+        self.kw_items = kw_items
+        self.track = faults is not None or on_overflow == "spill"
+        self.lb_on = cfg.strategy != "none" and not trig.never
+        self._carry0 = carry0
+
+    def initial_carry(self) -> PICCarry:
+        """The replay's state at step 0."""
+        return self._carry0
+
+    def _runner(self, chunk: int):
+        c = self.cfg
+        key = (_mesh_key(self.mesh), c.L, c.cx, c.cy, c.num_pes, c.k, c.vy0,
+               c.lb_every, c.strategy, self.kw_items, c.bytes_per_particle,
+               c.use_kernel, int(chunk), self.capacity, c.threads_per_node,
+               self.trig, self.faults, self.on_overflow, self.tel)
+        return _cached(_PIC_CACHE, key, lambda: _pic_chunk_runner(
+            self.mesh, c.L, c.cx, c.cy, c.num_pes, c.k, c.vy0, c.lb_every,
+            c.strategy, self.kw_items, c.bytes_per_particle, c.use_kernel,
+            int(chunk), self.capacity, c.threads_per_node, self.trig,
+            self.faults, self.on_overflow, self.tel))
+
+    def _args(self, carry: PICCarry, t0: int):
+        args = (jnp.asarray(int(t0), jnp.int32),) + tuple(carry[:10])
+        return args + ((carry.telemetry,) if self.tel else ())
+
+    def run_chunk(self, carry: PICCarry, t0: int, chunk: int):
+        """Advance ``chunk`` steps from ``carry``, the first numbered
+        ``t0``; the fault schedule and the trigger read these absolute
+        step indices.  Returns ``(new_carry, ys)`` once ``ys`` is on the
+        host: per step, the replicated scalars ``max_avg``, ``pe_max``,
+        ``ext``, ``int`` (handoff bytes), ``moved_share``,
+        ``migrated_bytes``, ``thread_max_avg``, ``fired``, ``forced`` (the
+        fire came from a health transition or a stranded chare), the
+        (C,) ``assignment`` after the step, the (D,) live ``count`` per
+        shard after the step and, for a resilient or spilling replay,
+        ``rejected`` and ``deferred``.
+
+        Traced as the host span ``lb/replay/chunk``; counts on
+        ``lb.replay.steps`` and ``lb.replay.fires``.  In strict mode a shard that needed more
+        than ``capacity`` slots raises ``ValueError`` (payload is never
+        dropped silently)."""
+        t0, chunk = int(t0), int(chunk)
+        if chunk < 1 or t0 < 0:
+            raise ValueError(f"need chunk >= 1 and t0 >= 0, got chunk="
+                             f"{chunk}, t0={t0}")
+        with compat.trace_annotation("lb/replay/chunk", t0=t0, steps=chunk):
+            out, ys = self._runner(chunk)(*self._args(carry, t0))
+            ys = jax.device_get(ys)
+        _REPLAY_STEPS.inc(chunk)
+        _REPLAY_FIRES.inc(float(ys["fired"].sum()))
+        # spill mode clamps inflow inside the exchange (counts <=
+        # capacity by construction, overflow surfaces as the deferred
+        # series); strict mode keeps the fail-loud contract
+        if self.on_overflow != "spill" and (ys["count"]
+                                            > self.capacity).any():
+            raise ValueError(
+                f"replay_capacity={self.capacity} overflowed (largest "
+                f"shard needed {int(ys['count'].max())} slots at some "
+                "step); the exchange would have dropped payload — raise "
+                f"replay_capacity (n_particles={self.cfg.n_particles} is "
+                "always safe) or use on_overflow='spill'")
+        return PICCarry(*out), ys
+
+    def compiled_text(self, carry: PICCarry, chunk: int) -> str:
+        """HLO text of the compiled ``chunk``-step runner."""
+        return (self._runner(int(chunk)).lower(*self._args(carry, 0))
+                .compile().as_text())
+
+    def particles(self, carry: PICCarry) -> Dict[str, np.ndarray]:
+        """The live particles on the host, shard by shard in slot order
+        (the single-device bucketed layout): ``x``, ``y``, ``vx``,
+        ``vy``, ``q`` and their ids ``perm``.  Only live slots leave the
+        device."""
+        counts = np.asarray(jax.device_get(carry.count)).reshape(-1)
+        out = {}
+        for name in ("x", "y", "vx", "vy", "q", "perm"):
+            shards = sorted(getattr(carry, name).addressable_shards,
+                            key=lambda s: s.index[0].start or 0)
+            out[name] = np.concatenate([
+                np.asarray(s.data[:counts[(s.index[0].start or 0)
+                                          // self.capacity]])
+                for s in shards])
+        return out
+
+    def plan_seconds(self, carry: PICCarry) -> float:
+        """Wall seconds of one single-device plan of the initial chare
+        problem (compiled first): the planning cost the ``CostModel``
+        charges at every fire, as the single-device scanned path
+        measures it."""
+        from repro.pic import chares as ch
+
+        c = self.cfg
+        slot = jnp.arange(self.D * self.capacity) % self.capacity
+        live = slot < jnp.repeat(carry.count, self.capacity)
+        loads0 = jnp.bincount(carry.chare, weights=live.astype(jnp.float32),
+                              length=c.cx * c.cy)
+        problem0 = ch.build_problem(
+            loads0, carry.assignment, L=c.L, cx=c.cx, cy=c.cy,
+            num_pes=c.num_pes, k=c.k, vy0=c.vy0, lb_period=c.lb_every,
+            bytes_per_particle=c.bytes_per_particle)
+        strat = core_engine.get_strategy(c.strategy)
+        strat.run(problem0, **dict(self.kw_items))     # warm the compile
+        return strat.run(problem0,
+                         **dict(self.kw_items)).info["plan_seconds"]
+
+    def package(self, carry: PICCarry, ys_chunks, *, wall_seconds: float,
+                cost, lb_est: float = 0.0) -> "PICResult":  # noqa: F821
+        """Final carry + the chunks' outputs, in step order →
+        ``PICResult`` (final positions in particle-id order)."""
+        from repro.pic import driver as pic_driver
+
+        c = self.cfg
+        ys = {k: np.concatenate([np.asarray(y[k]) for y in ys_chunks])
+              for k in ys_chunks[0]}
+        ma, pe_max, ext_b, int_b, mig, mig_bytes, tma, fired = (
+            np.asarray(ys[k], np.float64)
+            for k in ("max_avg", "pe_max", "ext", "int", "moved_share",
+                      "migrated_bytes", "thread_max_avg", "fired"))
+        lb_steps = fired > 0
+        lb_s_t = np.where(lb_steps, lb_est, 0.0)
+        step_s = (
+            pe_max * cost.t_particle
+            + (ext_b + mig_bytes) * cost.t_byte
+            + np.array([cost.lb_seconds(s_, c.strategy, c.num_pes)
+                        for s_ in lb_s_t])
+            / pic_driver._lb_amort(c, self.trig)
+        )
+        # undo the executed exchanges back to particle-id order
+        p = self.particles(carry)
+        fx, fy = np.empty_like(p["x"]), np.empty_like(p["y"])
+        fx[p["perm"]], fy[p["perm"]] = p["x"], p["y"]
+        return pic_driver.PICResult(
+            ma, ext_b, int_b, mig, mig_bytes,
+            float(lb_est * lb_steps.sum()), step_s, fx, fy,
+            scanned=True, wall_seconds=wall_seconds,
+            thread_max_avg=(tma if c.threads_per_node else None),
+            lb_steps=fired,
+            plan_rejected=(np.asarray(ys["rejected"], np.float64)
+                           if self.track else None),
+            deferred=(np.asarray(ys["deferred"], np.float64)
+                      if self.track else None),
+            telemetry=(obs_telemetry.snapshot(carry.telemetry, self.tel)
+                       if self.tel else None))
+
+
+def prepare_pic(cfg, particles=None) -> _PreparedPIC:
+    """Validate and stage a sharded PIC replay for chunk driving.
+
+    ``cfg`` is a ``PICConfig`` (its ``steps`` is not read: the caller
+    decides the schedule).  The mesh is the largest device count
+    dividing both ``n_particles`` and ``num_pes`` (``replay_shards``
+    overrides), each shard's slab holds ``replay_capacity`` slots (None:
+    ``n_particles``, always safe).  ``particles`` is ``(x, y, vx, vy,
+    q)``, (n,) device or host arrays in particle-id order; None makes
+    them from ``cfg`` (``pic.particles.initialize``).  Either way they
+    are put into the sharded slabs on the device, never staged as
+    padded slabs on the host."""
     from repro.pic import chares as ch
     from repro.pic import driver as pic_driver
     from repro.pic.particles import initialize
@@ -1142,120 +1381,67 @@ def run_pic_sharded(cfg, cost) -> "PICResult":  # noqa: F821
     on_overflow = getattr(cfg, "on_overflow", "strict")
     if on_overflow not in ("strict", "spill"):
         raise ValueError(f"unknown on_overflow mode {on_overflow!r}")
-
-    p = initialize(cfg.mode, cfg.L, n, k=cfg.k, vy0=cfg.vy0,
-                   rho=cfg.rho, seed=cfg.seed)
-    chare_id = np.asarray(ch.chare_of(p.x, p.y, cfg.L, cfg.cx, cfg.cy))
-    assignment = jnp.asarray(
-        ch.initial_mapping(cfg.cx, cfg.cy, cfg.num_pes, cfg.mapping),
-        jnp.int32)
-    n_chares = cfg.cx * cfg.cy
-
     kw_items = tuple(sorted((cfg.strategy_kwargs or {}).items()))
     trig = pic_driver._resolve_trigger(cfg)
-    lb_on = cfg.strategy != "none" and not trig.never
     faults, _ = _resolve_resilience(getattr(cfg, "faults", None), None, D,
                                     cfg.strategy, trig)
-    track = (faults is not None) or on_overflow == "spill"
     tel = obs_telemetry.resolve(getattr(cfg, "telemetry", None))
     tel = tel if tel.enabled else None
 
+    if particles is None:
+        p = initialize(cfg.mode, cfg.L, n, k=cfg.k, vy0=cfg.vy0,
+                       rho=cfg.rho, seed=cfg.seed)
+        particles = (p.x, p.y, p.vx, p.vy, p.q)
+    if len(particles) != 5 or any(np.shape(a) != (n,) for a in particles):
+        raise ValueError(f"particles must be five ({n},) arrays "
+                         "(x, y, vx, vy, q)")
+    sharded = jax.sharding.NamedSharding(mesh, P_(mesh.axis_names[0]))
+    particles = [jax.device_put(a if isinstance(a, jax.Array)
+                                else np.asarray(a, np.float32), sharded)
+                 for a in particles]
+    build = _cached(_PIC_CACHE, ("slabs", _mesh_key(mesh), n, capacity,
+                                 cfg.L, cfg.cx, cfg.cy),
+                    lambda: _slab_builder(mesh, n, capacity, cfg.L, cfg.cx,
+                                          cfg.cy))
+    *slabs, count = build(*particles)
+    assignment = jnp.asarray(ch.initial_mapping(
+        cfg.cx, cfg.cy, cfg.num_pes, cfg.mapping), jnp.int32)
+    carry0 = PICCarry(*slabs, count, assignment, trig.init_state(),
+                      (obs_telemetry.init_state(tel, cfg.num_pes)
+                       if tel else None))
+    return _PreparedPIC(cfg=cfg, mesh=mesh, capacity=capacity, trig=trig,
+                        faults=faults, on_overflow=on_overflow, tel=tel,
+                        kw_items=kw_items, carry0=carry0)
+
+
+def run_pic_sharded(cfg, cost) -> "PICResult":  # noqa: F821
+    """Mesh-sharded scanned PIC driver (``PICConfig(sharded_replay=True)``).
+
+    The whole run — push, handoff/byte accounting, trigger, planning and
+    the executed particle exchange — is one chunk of :func:`prepare_pic`
+    over ``cfg.steps`` steps: one compiled ``shard_map`` over the 1-D
+    ``"lb"`` mesh with the particle slabs row-sharded; the only host
+    contact is the particles in and the per-step metric series and final
+    live particles out.  Bit-for-bit the single-device scanned driver's
+    ``PICResult`` (including ``final_x/final_y`` restored to particle-id
+    order).  See the module docstring for the capacity rule; a
+    ``replay_capacity`` below the largest per-shard bucket total raises
+    ``ValueError`` after the run (payload is never dropped silently).
+
+    ``PICConfig.faults`` injects a ``runtime.resilience.FaultSchedule``
+    into the scan (health-masked trigger/planning, forced evacuation
+    fires, guarded plan adoption) and ``PICConfig.on_overflow="spill"``
+    swaps the exchange for the admission-clamped spill ring (overflow
+    particles stay on their source shard and drain on later fires);
+    either adds the ``plan_rejected`` / ``deferred`` per-step series to
+    the result.  Defaults leave the trace bit-for-bit unchanged."""
+    prep = prepare_pic(cfg)
+    carry = prep.initial_carry()
     # LB planning cost for the CostModel — measured once on the initial
     # snapshot, exactly as the single-device scanned path charges it
-    lb_est = 0.0
-    if lb_on:
-        loads0 = histogram(jnp.asarray(chare_id), jnp.ones(n), C=n_chares,
-                           use_kernel=cfg.use_kernel)
-        problem0 = ch.build_problem(
-            loads0, assignment, L=cfg.L, cx=cfg.cx, cy=cfg.cy,
-            num_pes=cfg.num_pes, k=cfg.k, vy0=cfg.vy0,
-            lb_period=cfg.lb_every,
-            bytes_per_particle=cfg.bytes_per_particle)
-        strat = core_engine.get_strategy(cfg.strategy)
-        strat.run(problem0, **dict(kw_items))          # warm the compile
-        lb_est = strat.run(problem0, **dict(kw_items)).info["plan_seconds"]
-
-    runner = _cached(
-        _PIC_CACHE,
-        (_mesh_key(mesh), cfg.L, cfg.cx, cfg.cy, cfg.num_pes, cfg.k,
-         cfg.vy0, cfg.lb_every, cfg.strategy, kw_items,
-         cfg.bytes_per_particle, cfg.use_kernel, cfg.steps, capacity,
-         cfg.threads_per_node, trig, faults, on_overflow, tel),
-        lambda: _pic_runner(mesh, cfg.L, cfg.cx, cfg.cy, cfg.num_pes,
-                            cfg.k, cfg.vy0, cfg.lb_every, cfg.strategy,
-                            kw_items, cfg.bytes_per_particle,
-                            cfg.use_kernel, cfg.steps, capacity,
-                            cfg.threads_per_node, trig, faults,
-                            on_overflow, tel))
-
-    slabs = _pad_slabs(
-        (p.x, p.y, p.vx, p.vy, p.q, chare_id,
-         np.arange(n, dtype=np.int32)), n, D, capacity)
-    count0 = jnp.full((D,), n // D, jnp.int32)
-
+    lb_est = prep.plan_seconds(carry) if prep.lb_on else 0.0
     t_start = time.perf_counter()
-    out = runner(*slabs, count0, assignment)
-    out = jax.device_get(out)
+    carry, ys = prep.run_chunk(carry, 0, cfg.steps)
     wall = time.perf_counter() - t_start
-
-    if tel:
-        obs_state = obs_telemetry.TelemetryState(*out[-3:])
-        out = out[:-3]
-    else:
-        obs_state = None
-    if track:
-        (ma, pe_max, ext_b, int_b, mig, mig_bytes, tma, fired, counts_ts,
-         rej, deferred, x_out, y_out, perm_out, counts) = out
-    else:
-        (ma, pe_max, ext_b, int_b, mig, mig_bytes, tma, fired, counts_ts,
-         x_out, y_out, perm_out, counts) = out
-        rej = deferred = None
-    counts_ts = np.asarray(counts_ts)              # (T, D) needed slots
-    # spill mode clamps inflow inside the exchange (counts <= capacity
-    # by construction, overflow surfaces as the deferred series); strict
-    # mode keeps the fail-loud contract
-    if on_overflow != "spill" and (counts_ts > capacity).any():
-        raise ValueError(
-            f"replay_capacity={capacity} overflowed (largest shard "
-            f"needed {int(counts_ts.max())} slots at some step); the "
-            "exchange would have dropped payload — raise replay_capacity "
-            f"(n_particles={n} is always safe) or use "
-            "on_overflow='spill'")
-
-    ma, pe_max, ext_b, int_b, mig, mig_bytes, tma, fired = (
-        np.asarray(a, np.float64)
-        for a in (ma, pe_max, ext_b, int_b, mig, mig_bytes, tma, fired))
-    lb_steps = fired > 0
-    lb_s_t = np.where(lb_steps, lb_est, 0.0)
-    step_s = (
-        pe_max * cost.t_particle
-        + (ext_b + mig_bytes) * cost.t_byte
-        + np.array([cost.lb_seconds(s_, cfg.strategy, cfg.num_pes)
-                    for s_ in lb_s_t]) / pic_driver._lb_amort(cfg, trig)
-    )
-    # concatenate the per-shard valid prefixes (the single-device slot
-    # layout), then undo the executed exchanges back to particle-id order
-    counts = np.asarray(counts).reshape(-1)
-    xs = np.concatenate([np.asarray(x_out)[d * capacity:
-                                           d * capacity + counts[d]]
-                         for d in range(D)])
-    ys_ = np.concatenate([np.asarray(y_out)[d * capacity:
-                                            d * capacity + counts[d]]
-                          for d in range(D)])
-    perm = np.concatenate([np.asarray(perm_out)[d * capacity:
-                                                d * capacity + counts[d]]
-                           for d in range(D)])
-    fx, fy = np.empty_like(xs), np.empty_like(ys_)
-    fx[perm], fy[perm] = xs, ys_
-    return pic_driver.PICResult(
-        ma, ext_b, int_b, mig, mig_bytes,
-        float(lb_est * lb_steps.sum()), step_s, fx, fy,
-        scanned=True, wall_seconds=wall,
-        thread_max_avg=(tma if cfg.threads_per_node else None),
-        lb_steps=fired,
-        plan_rejected=(None if rej is None
-                       else np.asarray(rej, np.float64)),
-        deferred=(None if deferred is None
-                  else np.asarray(deferred, np.float64)),
-        telemetry=(obs_telemetry.snapshot(obs_state, tel)
-                   if tel else None))
+    return prep.package(carry, [ys], wall_seconds=wall, cost=cost,
+                        lb_est=lb_est)
